@@ -27,7 +27,7 @@ from repro.configs import get_config
 from repro.configs.base import EncoderConfig, INPUT_SHAPES
 import repro.configs.registry as reg
 from repro.launch import sharding as shardlib, specs as speclib
-from repro.launch.dryrun import collective_bytes, cost_analysis_dict
+from repro.launch.dryrun import collective_bytes
 from repro.launch.mesh import make_production_mesh
 from repro.optim import get_optimizer
 from repro.train.steps import TrainState, make_train_step
@@ -77,7 +77,7 @@ def measure_pure_dp(nl: int, mesh):
     state = TrainState(params=p_sds, opt_state=o_sds,
                        step=speclib.sds((), jnp.int32, mesh))
     c = jax.jit(step, donate_argnums=(0,)).lower(state, batch_sds).compile()
-    ca = cost_analysis_dict(c)
+    ca = c.cost_analysis()
     return {
         "flops": float(ca["flops"]),
         "bytes": float(ca["bytes accessed"]),

@@ -29,7 +29,7 @@ import jax
 
 from repro.configs import ARCH_IDS, INPUT_SHAPES, get_config
 from repro.configs.base import EncoderConfig
-from repro.launch.dryrun import collective_bytes, cost_analysis_dict, lower_pair
+from repro.launch.dryrun import collective_bytes, lower_pair
 from repro.launch.mesh import make_production_mesh
 
 
@@ -69,7 +69,7 @@ def measure(arch, shape_name, cfg, mesh, sharding_mode="fsdp2d"):
     lowered, _ = lower_pair(arch, shape_name, mesh, cfg=cfg,
                             sharding_mode=sharding_mode)
     compiled = lowered.compile()
-    cost = cost_analysis_dict(compiled)
+    cost = compiled.cost_analysis()
     coll = collective_bytes(compiled.as_text())
     return {
         "flops": float(cost.get("flops", 0.0)),

@@ -7,7 +7,7 @@ Three estimation modes (``SatelliteComputeProfile.mode``):
              jax needed, the default.
   compiled   exact XLA ``cost_analysis`` FLOPs/bytes of the lowered
              smoke-config train step (``launch/dryrun``'s
-             ``cost_analysis_dict``) at a reduced compile shape, scaled
+             ``lower_pair``) at a reduced compile shape, scaled
              linearly in tokens to the profile's shape (the same
              linear-in-tokens assumption the analytic model makes).
   measured   wall-clock of one real jitted smoke step on this host
@@ -98,11 +98,13 @@ def compiled_step_cost(arch_id: str, shape_name: str) -> StepCost:
 
     Lowers + compiles the smoke config at the reduced compile shape on
     a single-device mesh (jax is imported lazily — analytic-mode users
-    never pay it), reads ``cost_analysis_dict`` and scales FLOPs and
+    never pay it), reads ``cost_analysis()`` and scales FLOPs and
     bytes linearly from compile-shape tokens to the profile shape's."""
+    import jax
+    from jax.sharding import AxisType
+
     import repro.configs.base as base
-    from repro.launch.dryrun import cost_analysis_dict, lower_pair
-    from repro.launch.mesh import make_mesh_compat
+    from repro.launch.dryrun import lower_pair
 
     shape = INPUT_SHAPES[shape_name]
     small = dataclasses.replace(
@@ -113,11 +115,13 @@ def compiled_step_cost(arch_id: str, shape_name: str) -> StepCost:
     )
     base.INPUT_SHAPES[small.name] = small
     try:
-        mesh = make_mesh_compat((1, 1), ("data", "model"))
+        mesh = jax.make_mesh(
+            (1, 1), ("data", "model"), axis_types=(AxisType.Auto,) * 2
+        )
         lowered, _ = lower_pair(
             arch_id, small.name, mesh, cfg=get_smoke_config(arch_id)
         )
-        cost = cost_analysis_dict(lowered.compile())
+        cost = lowered.compile().cost_analysis() or {}
     finally:
         base.INPUT_SHAPES.pop(small.name, None)
     scale = _tokens(shape) / _tokens(small)

@@ -16,6 +16,7 @@ knowledge across orbits.
 from __future__ import annotations
 
 import dataclasses
+import zlib
 from typing import Optional, Tuple
 
 import numpy as np
@@ -77,7 +78,11 @@ def make_classification_dataset(
         shape = (32, 32, 3)
     else:
         raise ValueError(f"unknown kind {kind!r}")
-    pattern_rng = np.random.default_rng(pattern_seed + hash(kind) % 1000)
+    # crc32, not hash(): str hashes are salted per process, which would
+    # make the class patterns differ from run to run
+    pattern_rng = np.random.default_rng(
+        pattern_seed + zlib.crc32(kind.encode()) % 1000
+    )
     patterns = _class_pattern(pattern_rng, num_classes, shape)
     rng = np.random.default_rng(seed)
     y = rng.integers(0, num_classes, size=num_samples).astype(np.int32)

@@ -8,25 +8,30 @@
   * ``ssd``        — Mamba2 SSD chunked scan.
 
 Each kernel ships as <name>.py (pl.pallas_call + BlockSpec), <name>_ops.py
-(jit'd wrapper with interpret fallback on CPU) and <name>_ref.py (pure-jnp
-oracle used by the allclose test sweeps).
+(jit'd wrapper that picks the execution mode with ``interpret_mode``) and
+<name>_ref.py (pure-jnp oracle used by the allclose test sweeps).
 """
+import jax
 
 
-def tpu_compiler_params(**kwargs):
-    """Build Mosaic compiler params across the pltpu rename.
+def interpret_mode() -> bool:
+    """Whether the kernels run in Pallas interpret mode on this backend.
 
-    JAX 0.4.x exposes ``pltpu.TPUCompilerParams``; newer releases renamed
-    it to ``pltpu.CompilerParams``.  Kernels must work on both.
+    Mosaic-compiled on ``tpu``; interpreted on ``cpu``, where the tests
+    run.  Any other backend raises: a kernel never silently interprets
+    on a device it was not written for.
     """
-    from jax.experimental.pallas import tpu as pltpu
-
-    cls = getattr(pltpu, "CompilerParams", None)
-    if cls is None:
-        cls = pltpu.TPUCompilerParams
-    return cls(**kwargs)
+    backend = jax.default_backend()
+    if backend == "tpu":
+        return False
+    if backend == "cpu":
+        return True
+    raise RuntimeError(
+        f"Pallas kernels run compiled on tpu or interpreted on cpu; "
+        f"the default backend is {backend!r}"
+    )
 
 
 from repro.kernels import aggregate_ops, flash_ops, ssd_ops  # noqa: E402
 
-__all__ = ["aggregate_ops", "flash_ops", "ssd_ops", "tpu_compiler_params"]
+__all__ = ["aggregate_ops", "flash_ops", "interpret_mode", "ssd_ops"]

@@ -38,8 +38,12 @@ def aggregate_flat(
 ) -> jnp.ndarray:
     """Weighted sum over the leading axis; returns (N,)."""
     k, n = x.shape
-    block_n = min(block_n, n)
-    # pad N to a block multiple
+    # Mosaic tiles the (BLOCK_N,) output over 128 lanes times the dtype's
+    # sublane packing (1 row per 32-bit word for f32, 2 for bf16); a
+    # block that ends mid-tile is refused, so a small N is padded to
+    # whole tiles rather than made one ragged block.
+    tile = 128 * max(1, 4 // x.dtype.itemsize)
+    block_n = min(block_n, -(-n // tile) * tile)
     n_pad = (-n) % block_n
     if n_pad:
         x = jnp.pad(x, ((0, 0), (0, n_pad)))

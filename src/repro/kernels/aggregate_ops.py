@@ -1,8 +1,8 @@
 """jit'd wrapper: pytree-level weighted aggregation through the kernel.
 
-On CPU (this container) the kernel runs in interpret mode; on TPU it
-compiles to a Mosaic kernel.  ``aggregate_pytree`` flattens every leaf,
-concatenates into one (K, N) stream (one kernel launch instead of
+The kernel compiles to Mosaic on TPU and runs in interpret mode on CPU
+(``repro.kernels.interpret_mode``).  ``aggregate_pytree`` flattens every
+leaf, concatenates into one (K, N) stream (one kernel launch instead of
 hundreds of tiny ones) and unflattens the result.
 """
 from __future__ import annotations
@@ -13,13 +13,10 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro.kernels import interpret_mode
 from repro.kernels.aggregate import aggregate_flat
 
 PyTree = Any
-
-
-def _on_tpu() -> bool:
-    return jax.default_backend() == "tpu"
 
 
 def aggregate_pytree(stacked: PyTree, weights: jnp.ndarray) -> PyTree:
@@ -33,7 +30,7 @@ def aggregate_pytree(stacked: PyTree, weights: jnp.ndarray) -> PyTree:
     flat = jnp.concatenate(
         [l.reshape(k, -1).astype(common) for l in leaves], axis=1
     )
-    agg = aggregate_flat(flat, weights, interpret=not _on_tpu())
+    agg = aggregate_flat(flat, weights, interpret=interpret_mode())
     outs = []
     off = 0
     for shape, size, dt in zip(shapes, sizes, dtypes):

@@ -1,18 +1,13 @@
-"""jit'd wrapper for the flash-attention kernel with CPU interpret
-fallback; the model layer calls this when attn_impl="pallas"."""
+"""jit'd wrapper for the flash-attention kernel (Mosaic on TPU, interpret
+mode on CPU); the model layer calls this when attn_impl="pallas"."""
 from __future__ import annotations
 
 from typing import Optional
 
-import jax
 import jax.numpy as jnp
 
 from repro.kernels import flash as _flash
-from repro.kernels.flash_ref import flash_attention_ref
-
-
-def _on_tpu() -> bool:
-    return jax.default_backend() == "tpu"
+from repro.kernels import interpret_mode
 
 
 def flash_attention(
@@ -26,11 +21,13 @@ def flash_attention(
     s = q.shape[1]
     block = 128 if s % 128 == 0 else (64 if s % 64 == 0 else None)
     if block is None:
-        # ragged sequence: fall back to the oracle
-        return flash_attention_ref(q, k, v, causal, window, logit_soft_cap)
+        raise ValueError(
+            f"flash attention needs a sequence length divisible by 64, "
+            f"got {s}; use attn_impl='chunked' for ragged lengths"
+        )
     return _flash.flash_attention(
         q, k, v, causal=causal, window=window,
         logit_soft_cap=logit_soft_cap,
         block_q=block, block_k=block,
-        interpret=not _on_tpu(),
+        interpret=interpret_mode(),
     )

@@ -19,8 +19,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels import tpu_compiler_params
-
 
 def _ssd_kernel(a_ref, x_ref, dt_ref, b_ref, c_ref, y_ref, state_scr,
                 *, chunk: int):
@@ -38,27 +36,33 @@ def _ssd_kernel(a_ref, x_ref, dt_ref, b_ref, c_ref, y_ref, state_scr,
 
     xdt = x * dt                                 # dt-weighted input
     a = dt * a_h                                 # (Q, 1) log-decay
-    a_cum = jnp.cumsum(a[:, 0])                  # (Q,)
 
-    # within-chunk decay matrix L[i, j] = exp(acum_i - acum_j), i >= j
-    diff = a_cum[:, None] - a_cum[None, :]
+    # Inclusive prefix sum of the log-decay, as a row and as a column.
+    # Mosaic has no cumsum, so it is a masked (Q, Q) reduction instead:
+    # row[j] = sum_{i <= j} a_i, and the column is the row's diagonal.
     li = jax.lax.broadcasted_iota(jnp.int32, (chunk, chunk), 0)
     lj = jax.lax.broadcasted_iota(jnp.int32, (chunk, chunk), 1)
-    L = jnp.where(li >= lj, jnp.exp(diff), 0.0)
+    a_cum_row = jnp.sum(jnp.where(li <= lj, a, 0.0), axis=0,
+                        keepdims=True)           # (1, Q)
+    a_cum = jnp.sum(jnp.where(li == lj, a_cum_row, 0.0), axis=1,
+                    keepdims=True)               # (Q, 1)
+
+    # within-chunk decay matrix L[i, j] = exp(acum_i - acum_j), i >= j
+    L = jnp.where(li >= lj, jnp.exp(a_cum - a_cum_row), 0.0)
 
     scores = jnp.dot(cm, bm.T) * L               # (Q, Q) masked CB^T
     y_diag = jnp.dot(scores, xdt)                # (Q, P)
 
     # carry-in from previous chunks' state
     state = state_scr[...]                        # (P, N)
-    y_off = jnp.exp(a_cum)[:, None] * jnp.dot(cm, state.T)  # (Q, P)
+    y_off = jnp.exp(a_cum) * jnp.dot(cm, state.T)  # (Q, P)
 
     y_ref[0, 0] = (y_diag + y_off).astype(y_ref.dtype)
 
     # state update: state' = exp(a_tot) * state + sum_q decay_q B_q (x) xdt_q
-    a_tot = a_cum[-1]
-    decay_to_end = jnp.exp(a_tot - a_cum)         # (Q,)
-    new_contrib = jnp.dot((xdt * decay_to_end[:, None]).T, bm)  # (P, N)
+    a_tot = jnp.sum(a, axis=0, keepdims=True)     # (1, 1)
+    decay_to_end = jnp.exp(a_tot - a_cum)         # (Q, 1)
+    new_contrib = jnp.dot((xdt * decay_to_end).T, bm)  # (P, N)
     state_scr[...] = state * jnp.exp(a_tot) + new_contrib
 
 
@@ -106,7 +110,7 @@ def ssd_scan(
                                lambda bi, hi, ci: (bi, hi, ci, 0)),
         out_shape=jax.ShapeDtypeStruct((b, h, s, p), x.dtype),
         scratch_shapes=[pltpu.VMEM((p, n), jnp.float32)],
-        compiler_params=tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary"),
         ),
         interpret=interpret,

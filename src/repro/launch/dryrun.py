@@ -32,7 +32,7 @@ import numpy as np
 from repro.configs import ARCH_IDS, INPUT_SHAPES, build_model, get_config
 from repro.configs.base import ArchConfig, InputShape
 from repro.launch import specs as speclib
-from repro.launch.mesh import make_production_mesh, use_mesh_compat
+from repro.launch.mesh import make_production_mesh
 from repro.optim import get_optimizer
 from repro.train.steps import (
     make_prefill_step,
@@ -52,18 +52,6 @@ _DTYPE_BYTES = {
     "bf16": 2, "f16": 2, "f32": 4, "f64": 8, "s32": 4, "u32": 4,
     "s8": 1, "u8": 1, "pred": 1, "s64": 8, "u64": 8,
 }
-
-
-def cost_analysis_dict(compiled) -> Dict[str, Any]:
-    """compiled.cost_analysis() as a flat dict on every JAX version.
-
-    0.4.x returns a one-element list of per-computation dicts; newer
-    releases return the dict directly.
-    """
-    cost = compiled.cost_analysis()
-    if isinstance(cost, (list, tuple)):
-        cost = cost[0] if cost else {}
-    return cost or {}
 
 
 def collective_bytes(hlo_text: str) -> Dict[str, float]:
@@ -124,7 +112,7 @@ def lower_pair(
         param_axes, opt_axes = fsdp_axes, fsdp_axes
     meta["sharding"] = sharding_mode
 
-    with use_mesh_compat(mesh):
+    with jax.set_mesh(mesh):
         if shape.kind == "train":
             state_sds = speclib.state_specs(model, cfg, mesh, param_axes,
                                             opt_fsdp_axes=opt_axes)
@@ -163,7 +151,7 @@ def run_pair(arch: str, shape_name: str, *, multi_pod: bool = False,
     t_compile = time.time() - t1
 
     mem = compiled.memory_analysis()
-    cost = cost_analysis_dict(compiled)
+    cost = compiled.cost_analysis()
     hlo = compiled.as_text()
     coll = collective_bytes(hlo)
 

@@ -10,44 +10,14 @@ from __future__ import annotations
 from typing import Tuple
 
 import jax
-
-
-def _axis_type_kwargs(n: int) -> dict:
-    """``axis_types=(Auto,) * n`` where supported.
-
-    ``jax.sharding.AxisType`` (and the ``axis_types`` kwarg of
-    ``jax.make_mesh``) only exist on newer JAX releases; 0.4.x meshes are
-    implicitly Auto, so omitting the kwarg is the exact equivalent.
-    """
-    axis_type = getattr(jax.sharding, "AxisType", None)
-    if axis_type is None:
-        return {}
-    return {"axis_types": (axis_type.Auto,) * n}
-
-
-def make_mesh_compat(shape, axes):
-    """jax.make_mesh with Auto axis types on any supported JAX version."""
-    return jax.make_mesh(shape, axes, **_axis_type_kwargs(len(shape)))
-
-
-def use_mesh_compat(mesh):
-    """Context manager activating ``mesh``, on any supported JAX version.
-
-    Newer JAX exposes ``jax.set_mesh``; on 0.4.x the Mesh object itself
-    is the context manager (all our shardings are explicit NamedShardings
-    anyway, so the context only needs to exist, not alter semantics).
-    """
-    set_mesh = getattr(jax, "set_mesh", None)
-    if set_mesh is not None:
-        return set_mesh(mesh)
-    return mesh
+from jax.sharding import AxisType
 
 
 def make_production_mesh(*, multi_pod: bool = False):
     """16x16 = 256 chips per pod; 2 pods = 512 chips multi-pod."""
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return make_mesh_compat(shape, axes)
+    return jax.make_mesh(shape, axes, axis_types=(AxisType.Auto,) * len(shape))
 
 
 def make_fedleo_mesh(*, num_orbits: int = 4, multi_pod: bool = False):
@@ -61,10 +31,12 @@ def make_fedleo_mesh(*, num_orbits: int = 4, multi_pod: bool = False):
     the data axis (num_orbits x (16/num_orbits) x 16).
     """
     if multi_pod:
-        return make_mesh_compat((2, 16, 16), ("orbit", "data", "model"))
-    assert 16 % num_orbits == 0, "orbit count must divide the data axis"
-    return make_mesh_compat(
-        (num_orbits, 16 // num_orbits, 16), ("orbit", "data", "model")
+        shape = (2, 16, 16)
+    else:
+        assert 16 % num_orbits == 0, "orbit count must divide the data axis"
+        shape = (num_orbits, 16 // num_orbits, 16)
+    return jax.make_mesh(
+        shape, ("orbit", "data", "model"), axis_types=(AxisType.Auto,) * 3
     )
 
 

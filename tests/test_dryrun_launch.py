@@ -20,10 +20,11 @@ import json
 import jax
 import jax.numpy as jnp
 from repro.configs import get_smoke_config
-from repro.launch.dryrun import collective_bytes, cost_analysis_dict, lower_pair
-from repro.launch.mesh import make_mesh_compat
+from jax.sharding import AxisType
+from repro.launch.dryrun import collective_bytes, lower_pair
 
-mesh = make_mesh_compat((2, 2, 2), ("pod", "data", "model"))
+mesh = jax.make_mesh((2, 2, 2), ("pod", "data", "model"),
+                     axis_types=(AxisType.Auto,) * 3)
 out = {}
 for arch, shape in [("gemma-7b", "train_4k"),
                     ("kimi-k2-1t-a32b", "train_4k"),
@@ -38,7 +39,7 @@ for arch, shape in [("gemma-7b", "train_4k"),
     try:
         lowered, meta = lower_pair(arch, shape, mesh, cfg=cfg)
         compiled = lowered.compile()
-        cost = cost_analysis_dict(compiled)
+        cost = compiled.cost_analysis()
         coll = collective_bytes(compiled.as_text())
         out[f"{arch}|{shape}"] = {
             "ok": True,

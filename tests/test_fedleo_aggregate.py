@@ -54,20 +54,36 @@ def _max_err(a: TrainState, b: TrainState) -> float:
     return max(jax.tree_util.tree_leaves(errs), default=0.0)
 
 
+def _bf16_ulp(x) -> np.ndarray:
+    """One unit in the last place of each element of a bf16 array: bf16
+    keeps 8 significant bits to f32's 24, so its spacing is 2**16 times
+    f32's."""
+    return np.spacing(np.abs(np.asarray(x, np.float32))) * 2.0 ** 16
+
+
 @pytest.mark.parametrize("dtype,tol", [
     (jnp.float32, 1e-6),
-    (jnp.bfloat16, 1e-6),   # both paths accumulate in f32
+    (jnp.bfloat16, 1e-6),
 ])
 def test_kernel_matches_reference_on_real_pytree(dtype, tol):
     state = _stacked_cnn_state(dtype)
     w = jnp.array([1.0, 2.0, 3.0, 4.0])
     ref = make_fedleo_aggregate(use_kernel=False)(state, w)
     ker = make_fedleo_aggregate(use_kernel=True)(state, w)
-    assert _max_err(ref, ker) <= tol
-    # dtypes survive the kernel round-trip
+    if dtype == jnp.float32:
+        assert _max_err(ref, ker) <= tol
     for a, b in zip(jax.tree_util.tree_leaves(ref.params),
                     jax.tree_util.tree_leaves(ker.params)):
+        # dtypes survive the kernel round-trip
         assert a.dtype == b.dtype
+        if dtype == jnp.bfloat16:
+            # Both paths sum in f32, but the kernel's fused loop rounds
+            # each multiply-add once where the reference rounds the
+            # product and the sum apart.  Where that last-bit f32
+            # difference straddles a bf16 rounding boundary, the bf16
+            # output moves by one ulp, so bf16 is held to one output ulp.
+            err = np.abs(np.asarray(a, np.float32) - np.asarray(b, np.float32))
+            assert np.all(err <= tol + _bf16_ulp(a))
 
 
 def test_kernel_matches_flat_ref_ground_truth():

@@ -77,6 +77,27 @@ def test_train_test_same_distribution():
         assert r > 0.5, f"class {c} corr {r}"
 
 
+def test_classification_data_is_the_same_in_every_process():
+    """The data depends on its seeds alone, not on the process's salted
+    str hashes."""
+    import subprocess
+    import sys
+
+    code = ("from repro.data import make_classification_dataset as m; "
+            "print(repr(float(m('mnist-like', 8, seed=3).x.sum())))")
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    outs = {
+        subprocess.run(
+            [sys.executable, "-c", code], cwd=root, check=True,
+            capture_output=True, text=True, timeout=120,
+            env=dict(os.environ, PYTHONHASHSEED=str(salt), PYTHONPATH="src",
+                     JAX_PLATFORMS="cpu"),
+        ).stdout
+        for salt in (1, 2)
+    }
+    assert len(outs) == 1
+
+
 def test_noniid_partition_matches_paper():
     """§V-A: 2 orbits -> 4 classes; 3 orbits -> remaining 6 classes."""
     ds = make_classification_dataset("mnist-like", 2000, seed=1)
