@@ -27,6 +27,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro.obs import span
+
 PyTree = Any
 
 
@@ -39,17 +41,25 @@ def weighted_average(
       stacked: pytree with leaves of shape (K, ...).
       weights: (K,) nonnegative weights; will be normalized to sum to 1.
       use_kernel: route through the Pallas aggregation kernel (TPU).
+
+    Runs in a ``repro.aggregate`` profiler span whose ``bytes`` stat is
+    the size of the stacked leaves it reads.
     """
-    w = weights / jnp.sum(weights)
-    if use_kernel:
-        from repro.kernels import aggregate_ops
+    leaves = jax.tree_util.tree_leaves(stacked)
+    nbytes = sum(x.size * x.dtype.itemsize for x in leaves)
+    with span("aggregate", bytes=nbytes):
+        w = weights / jnp.sum(weights)
+        if use_kernel:
+            from repro.kernels import aggregate_ops
 
-        return aggregate_ops.aggregate_pytree(stacked, w)
+            return aggregate_ops.aggregate_pytree(stacked, w)
 
-    def leaf(x: jnp.ndarray) -> jnp.ndarray:
-        return jnp.tensordot(w.astype(jnp.float32), x.astype(jnp.float32), axes=1).astype(x.dtype)
+        def leaf(x: jnp.ndarray) -> jnp.ndarray:
+            return jnp.tensordot(
+                w.astype(jnp.float32), x.astype(jnp.float32), axes=1
+            ).astype(x.dtype)
 
-    return jax.tree_util.tree_map(leaf, stacked)
+        return jax.tree_util.tree_map(leaf, stacked)
 
 
 def partial_aggregate(

@@ -35,6 +35,7 @@ from repro.obs import (
     TraceRecorder,
     format_round_line,
     round_log_record,
+    span,
 )
 from repro.orbits.constellation import (
     ConstellationConfig,
@@ -332,18 +333,22 @@ class FLStrategy:
         skipped.  ``run`` drives this for standalone strategies; the
         multi-tenant ``JobScheduler`` calls it directly to interleave
         rounds of concurrent jobs (a single job through the scheduler
-        executes the identical call sequence — bit-identical)."""
-        # simulated time is monotone: bookings that ended before this
-        # round can never affect another fit (under a shared ledger the
-        # floor callback holds back expiry for slower concurrent jobs)
-        floor = t if self.release_floor_fn is None else self.release_floor_fn(t)
-        self.env.release_before(floor)
-        t_next, events = self.step(t)
-        if t_next is None or t_next <= t:
-            self._completed = False
-            return None
-        self.round_index += 1
-        metrics = self.task.evaluate(self.global_params)
+        executes the identical call sequence — bit-identical).  The
+        release, the step and the evaluation run in a ``repro.round``
+        profiler span."""
+        with span("round"):
+            # simulated time is monotone: bookings that ended before this
+            # round can never affect another fit (under a shared ledger
+            # the floor callback holds back expiry for slower jobs)
+            floor = (t if self.release_floor_fn is None
+                     else self.release_floor_fn(t))
+            self.env.release_before(floor)
+            t_next, events = self.step(t)
+            if t_next is None or t_next <= t:
+                self._completed = False
+                return None
+            self.round_index += 1
+            metrics = self.task.evaluate(self.global_params)
         decomposition = RoundDecomposition(
             round_index=self.round_index,
             t_start=t,

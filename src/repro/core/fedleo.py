@@ -56,7 +56,7 @@ from repro.core.engine import FLStrategy, SimConfig
 from repro.core.fltask import FederatedTask
 from repro.core.propagation import ring_hops_matrix
 from repro.core.scheduling import ClusterSinkDecision, SinkDecision
-from repro.obs import decompose_group_plan
+from repro.obs import decompose_group_plan, span
 from repro.orbits.constellation import GroundStation, Satellite, WalkerDelta
 from repro.orbits.prediction import VisibilityPredictor
 from repro.orbits.topology import ISLTopology, get_isl_topology
@@ -401,7 +401,12 @@ class _SyncRoundMixin:
     on the strategy's resource ledger before the next group plans, so
     later sinks are priced against the residual station capacity —
     several sinks landing on one station's window now compete for its
-    resource blocks instead of overlapping for free."""
+    resource blocks instead of overlapping for free.
+
+    Each group runs in a ``repro.group`` profiler span, which holds
+    ``repro.plan`` (the sink scheduler), ``repro.commit`` (the booking),
+    then the task's ``repro.local_train`` and the ``repro.aggregate`` of
+    the partial."""
 
     def _sync_round(
         self,
@@ -427,23 +432,26 @@ class _SyncRoundMixin:
             # node-ordered client list (plane-major, slot order) so that
             # client i sits on the group's i-th satellite
             clients = [c for p in group for c in self.plane_clients(p)]
-            plan = plan_group(group, clients)
-            if plan is None:
-                return None, fail_event(group)
-            self.env.commit(plan.decision)
-            # typed phase decomposition of the committed plan (read-only
-            # on the plan: schedules are unaffected)
-            self._round_groups.append(decompose_group_plan(plan, t))
+            with span("group"):
+                with span("plan"):
+                    plan = plan_group(group, clients)
+                if plan is None:
+                    return None, fail_event(group)
+                with span("commit"):
+                    self.env.commit(plan.decision)
+                    # typed phase decomposition of the committed plan
+                    # (read-only on the plan: schedules are unaffected)
+                    self._round_groups.append(decompose_group_plan(plan, t))
 
-            stacked = task.local_train(
-                self.global_params, clients, self._next_rng()
-            )
-            counts = [task.num_samples(c) for c in clients]
-            partials.append(
-                aggregation.partial_aggregate(
-                    stacked, counts, use_kernel=sim.use_kernel
+                stacked = task.local_train(
+                    self.global_params, clients, self._next_rng()
                 )
-            )
+                counts = [task.num_samples(c) for c in clients]
+                partials.append(
+                    aggregation.partial_aggregate(
+                        stacked, counts, use_kernel=sim.use_kernel
+                    )
+                )
             group_counts.append(int(np.sum(counts)))
             group_hists.append(
                 np.sum([task.clients[c].histogram for c in clients], axis=0)

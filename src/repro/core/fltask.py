@@ -28,6 +28,7 @@ from repro.compute.fleet import FleetComputeModel
 from repro.data.partition import ClientData, stack_client_arrays
 from repro.data.synthetic import Dataset
 from repro.models import nn
+from repro.obs import span
 from repro.optim import Optimizer
 from repro.optim.optimizers import apply_updates
 
@@ -51,6 +52,13 @@ def cross_entropy_loss(logits: jnp.ndarray, labels: jnp.ndarray) -> jnp.ndarray:
     logp = jax.nn.log_softmax(logits, axis=-1)
     onehot = jax.nn.one_hot(labels, logits.shape[-1], dtype=logp.dtype)
     return -jnp.mean(jnp.sum(onehot * logp, axis=-1))
+
+
+def _epoch_batches(m: int, batch_size: int) -> Tuple[int, int]:
+    """(n_batches, batch_size) of one epoch over m samples; tiny clients
+    (m < b_k) take full-batch steps."""
+    bsz = min(batch_size, max(1, m))
+    return max(1, m // bsz), bsz
 
 
 def accuracy(logits: jnp.ndarray, labels: jnp.ndarray) -> jnp.ndarray:
@@ -129,13 +137,11 @@ class FederatedTask:
 
     def executed_batches(self, client_id: int) -> Tuple[int, int]:
         """(n_batches, batch_size) as ``_local_train_one`` executes
-        them: tiny clients (m < b_k) fall back to full-batch steps, so
-        the simulated clock must charge the samples actually processed
-        — not b_k.  For m >= b_k this is exactly eq. (11)'s
-        (m // b_k, b_k)."""
-        m = self.num_samples(client_id)
-        bsz = min(self.hp.batch_size, max(1, m))
-        return max(1, m // bsz), bsz
+        them on this client's own samples: tiny clients (m < b_k) fall
+        back to full-batch steps, so the simulated clock must charge the
+        samples actually processed — not b_k.  For m >= b_k this is
+        exactly eq. (11)'s (m // b_k, b_k)."""
+        return _epoch_batches(self.num_samples(client_id), self.hp.batch_size)
 
     def train_time_s(self, client_id: int) -> float:
         """Eq. (11): t_train(k) = I * n_k * b_k * c_k / f_k, charged
@@ -162,10 +168,8 @@ class FederatedTask:
         self, params: PyTree, x: jax.Array, y: jax.Array, rng: jax.Array
     ) -> PyTree:
         """I epochs of mini-batch SGD on one client (runs under vmap)."""
-        hp = self.hp
         m = x.shape[0]
-        bsz = min(hp.batch_size, m)   # tiny clients: full-batch steps
-        n_batches = max(1, m // bsz)
+        n_batches, bsz = _epoch_batches(m, self.hp.batch_size)
         opt_state = self.optimizer.init(params)
 
         def loss(p: PyTree, xb: jax.Array, yb: jax.Array) -> jax.Array:
@@ -200,16 +204,25 @@ class FederatedTask:
     ) -> PyTree:
         """Train the given global params on each listed client in parallel.
 
-        Returns stacked params with leading axis len(client_ids).
+        Returns stacked params with leading axis len(client_ids).  Runs
+        in a ``repro.local_train`` profiler span that carries the call's
+        sequential SGD ``steps`` and the ``samples`` trained over all
+        listed clients.
         """
         ids = np.asarray(list(client_ids))
-        stacked = jax.tree_util.tree_map(
-            lambda p: jnp.broadcast_to(p, (len(ids),) + p.shape), params
-        )
-        rngs = jax.random.split(rng, len(ids))
-        return self._local_train_vmapped(
-            stacked, self._x_stack[ids], self._y_stack[ids], rngs
-        )
+        # every client trains on the stack's padded length: the steps and
+        # batch size of the largest client, for each client of the call
+        n_batches, bsz = _epoch_batches(self._x_stack.shape[1],
+                                        self.hp.batch_size)
+        steps = self.sim_epochs * n_batches
+        with span("local_train", steps=steps, samples=len(ids) * steps * bsz):
+            stacked = jax.tree_util.tree_map(
+                lambda p: jnp.broadcast_to(p, (len(ids),) + p.shape), params
+            )
+            rngs = jax.random.split(rng, len(ids))
+            return self._local_train_vmapped(
+                stacked, self._x_stack[ids], self._y_stack[ids], rngs
+            )
 
     # --- evaluation ---------------------------------------------------------------
     def _eval(
@@ -222,10 +235,15 @@ class FederatedTask:
         }
 
     def evaluate(self, params: PyTree, max_samples: int = 1024) -> Dict[str, float]:
-        x = jnp.asarray(self.test_set.x[:max_samples])
-        y = jnp.asarray(self.test_set.y[:max_samples])
-        out = self._eval_jit(params, x, y)
-        return {k: float(v) for k, v in out.items()}
+        """Loss and accuracy of ``params`` on the test set, in a
+        ``repro.evaluate`` profiler span; ``repro.wait`` inside it is
+        the host blocked on the device for the numbers."""
+        with span("evaluate"):
+            x = jnp.asarray(self.test_set.x[:max_samples])
+            y = jnp.asarray(self.test_set.y[:max_samples])
+            out = self._eval_jit(params, x, y)
+            with span("wait"):
+                return {k: float(v) for k, v in out.items()}
 
     # --- client lookup ---------------------------------------------------------------
     def clients_on_plane(self, plane: int) -> List[int]:

@@ -8,8 +8,8 @@ stream through VMEM at full bandwidth with the accumulation in fp32.
 
 TPU adaptation: block shape (K, BLOCK_N) with BLOCK_N a multiple of the
 128-lane register width; K (the client axis) stays resident so each HBM
-byte of x is touched exactly once.  Weights live in SMEM (scalar
-prefetch) — they are K scalars.
+byte of x is touched exactly once.  The K weights come in as a (K, 1)
+VMEM block that every grid step maps to the same tile.
 """
 from __future__ import annotations
 
@@ -60,5 +60,6 @@ def aggregate_flat(
         out_specs=pl.BlockSpec((block_n,), lambda i: (i,)),
         out_shape=jax.ShapeDtypeStruct((n_total,), x.dtype),
         interpret=interpret,
+        name="aggregate_flat",
     )(w[:, None], x)
     return out[:n]

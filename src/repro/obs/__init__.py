@@ -8,6 +8,9 @@ and the CLI reporter (``python -m repro.obs.report``).
 
 Enable per run with ``SimConfig(trace=True)``; tracing is
 zero-interference — a traced run is bit-identical to an untraced one.
+
+``span`` (``repro.obs.spans``) is the other clock: wall-time spans of
+the round's host work, stamped by the JAX profiler while one runs.
 """
 from repro.obs.decomposition import (
     GroupDecomposition,
@@ -24,6 +27,7 @@ from repro.obs.trace import (
     format_round_line,
     round_log_record,
 )
+from repro.obs.spans import span
 from repro.obs.utilization import ledger_rb_utilization
 
 __all__ = [
@@ -39,4 +43,5 @@ __all__ = [
     "format_round_line",
     "round_log_record",
     "ledger_rb_utilization",
+    "span",
 ]

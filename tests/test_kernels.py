@@ -48,6 +48,27 @@ def test_aggregate_pytree_wrapper():
     assert out["b"][0].shape == (5,)
 
 
+@pytest.mark.parametrize("k", [5, 8])
+def test_aggregate_pytree_jit_matches_eager_bitwise(k):
+    """One jitted program gives the eager composition's bits (concatenate,
+    the kernel, per-leaf slices) on ragged leaves of mixed dtypes."""
+    from repro.kernels.aggregate_ops import aggregate_pytree
+
+    rng = np.random.default_rng(k)
+    shapes = [(3, 3, 5), (130,), (), (7, 17), (4097,)]
+    tree = [jnp.asarray(rng.standard_normal((k,) + s), jnp.float32)
+            for s in shapes]
+    tree.append(jnp.asarray(rng.standard_normal((k, 33)), jnp.bfloat16))
+    w = jnp.asarray(rng.random(k), jnp.float32)
+    w = w / jnp.sum(w)
+    jitted = aggregate_pytree(tree, w)
+    eager = aggregate_pytree.__wrapped__(tree, w)
+    for a, b in zip(jitted, eager):
+        assert a.dtype == b.dtype and a.shape == b.shape
+        np.testing.assert_array_equal(np.asarray(a, np.float32),
+                                      np.asarray(b, np.float32))
+
+
 # --- flash attention --------------------------------------------------------------
 @pytest.mark.parametrize(
     "b,s,h,g,d,causal,window",
