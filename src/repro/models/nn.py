@@ -11,6 +11,7 @@ regex) stay trivial.
 """
 from __future__ import annotations
 
+import functools
 from typing import Any, Dict, Optional, Sequence, Tuple
 
 import jax
@@ -76,7 +77,18 @@ def apply_conv_transpose(p: Dict, x: jnp.ndarray, stride: int = 2):
     return y
 
 
+@functools.partial(jax.custom_vjp, nondiff_argnums=(1,))
 def max_pool(x: jnp.ndarray, window: int = 2) -> jnp.ndarray:
+    """``window`` x ``window`` max-pool with stride ``window``, ``VALID``.
+
+    Its gradient goes to the first element of each window, in row-major
+    order, that equals the window's max: what ``reduce_window``'s own
+    gradient (a select-and-scatter with a ``>=`` select) gives, ties
+    included, but without a select-and-scatter, which is slow on the TPU."""
+    return _reduce_max(x, window)
+
+
+def _reduce_max(x: jnp.ndarray, window: int) -> jnp.ndarray:
     return jax.lax.reduce_window(
         x,
         -jnp.inf,
@@ -85,6 +97,64 @@ def max_pool(x: jnp.ndarray, window: int = 2) -> jnp.ndarray:
         window_strides=(1, window, window, 1),
         padding="VALID",
     )
+
+
+def _max_pool_fwd(x, window):
+    y = _reduce_max(x, window)
+    return y, (x, y)
+
+
+def _first_max(x: jnp.ndarray, y: jnp.ndarray, window: int) -> jnp.ndarray:
+    """Row-major position in its window of the first element equal to
+    the window's max ``y``, from strided slices of ``x``.  In int8, so
+    that the full-size copy the gradient reads is a quarter of an int32
+    one: on one TPU v5e the vmapped CNN step of 8 clients x 32 samples
+    took 1.29 ms so, 1.74 ms with int32."""
+    if window * window > 127:
+        raise ValueError(f"max_pool window {window} too large")
+    b, _, _, c = x.shape
+    ho, wo = y.shape[1], y.shape[2]
+    first = jnp.full(y.shape, window * window, jnp.int8)
+    for p in reversed(range(window * window)):
+        dy, dx = divmod(p, window)
+        xs = jax.lax.slice(
+            x, (0, dy, dx, 0),
+            (b, dy + (ho - 1) * window + 1, dx + (wo - 1) * window + 1, c),
+            (1, window, window, 1))
+        first = jnp.where(xs == y, jnp.int8(p), first)
+    return first
+
+
+def _upsample(a: jnp.ndarray, window: int, h: int, w: int, fill) -> jnp.ndarray:
+    """Each element of ``a`` repeated over its ``window`` x ``window``
+    block of an (h, w) map; rows and columns past the last whole window
+    get ``fill``.  A max over a window of the base-dilated ``a``: XLA
+    lowers this to one reduce-window, where a broadcast-and-reshape
+    becomes a separate, slower relayout on the TPU."""
+    ho, wo = a.shape[1], a.shape[2]
+    return jax.lax.reduce_window(
+        a, jnp.array(fill, a.dtype), jax.lax.max,
+        window_dimensions=(1, window, window, 1),
+        window_strides=(1, 1, 1, 1),
+        padding=((0, 0),
+                 (window - 1, h - ho * window + window - 1),
+                 (window - 1, w - wo * window + window - 1),
+                 (0, 0)),
+        base_dilation=(1, window, window, 1))
+
+
+def _max_pool_bwd(window, res, g):
+    x, y = res
+    h, w = x.shape[1], x.shape[2]
+    rows, cols = np.arange(h)[:, None], np.arange(w)[None, :]
+    pos = ((rows % window) * window + cols % window).astype(np.int8)
+    first = _upsample(_first_max(x, y, window), window, h, w, -1)
+    dx = jnp.where(pos[None, :, :, None] == first,
+                   _upsample(g, window, h, w, -jnp.inf), jnp.zeros((), g.dtype))
+    return (dx,)
+
+
+max_pool.defvjp(_max_pool_fwd, _max_pool_bwd)
 
 
 def init_layernorm(dim: int) -> Dict:
