@@ -30,15 +30,16 @@ def program_records(cell, built):
              for r in episodes.first], episodes.first_hours)
 
 
-def readings(cell, records, hours, replay):
+def readings(cell, test, records, hours, replay):
     from bench import cell as cells
 
-    out = cells.compare(records, replay)
+    out = cells.compare(cell, test, records, replay)
     out["schedule_gap"] = cells.schedule_gap(hours, cell.schedule_hours)
     return out
 
 
 def calibrate_seed(cell, seed: int, faults: bool) -> dict:
+    import jax
     import jax.numpy as jnp
 
     from bench import cell as cells
@@ -54,15 +55,17 @@ def calibrate_seed(cell, seed: int, faults: bool) -> dict:
     t1 = time.perf_counter()
     replay = cells.reference_replay(cell, clients, test, seeds, records)
     t2 = time.perf_counter()
-    out["program"] = readings(cell, records, hours, replay)
+    out["program"] = readings(cell, test, records, hours, replay)
     out["seconds"] = {"program": t1 - t0, "reference": t2 - t1}
     if faults:
         bf16 = cells.reference_replay(cell, clients, test, seeds, records,
                                       dtype=jnp.bfloat16)
         w0, p, losses = bf16
+        treedef = jax.tree_util.tree_structure(records[0].params)
         out["control_bfloat16"] = readings(
-            cell, [cells.Record(pp, ll, r.events)
-                   for pp, ll, r in zip(p, losses, records)], hours, replay)
+            cell, test, [cells.Record(treedef.unflatten(pp), ll, r.events)
+                         for pp, ll, r in zip(p, losses, records)],
+            hours, replay)
         runs = [(f, cell, planted.task_class(f)) for f in planted.FAULTS]
         runs.append(("worse_schedule", planted.worse_schedule(cell), None))
         for fault, fcell, task_cls in runs:
@@ -71,7 +74,7 @@ def calibrate_seed(cell, seed: int, faults: bool) -> dict:
             frec, fhours = program_records(fcell, built)
             del built
             gc.collect()
-            out[fault] = readings(cell, frec, fhours, replay)
+            out[fault] = readings(cell, test, frec, fhours, replay)
         out["seconds"]["control_and_faults"] = time.perf_counter() - t2
     return out
 

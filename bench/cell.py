@@ -128,7 +128,8 @@ class CompileMonitor:
 
 @dataclasses.dataclass
 class Counters:
-    """What the benchmark's own spans count, reset at the window's start."""
+    """What the benchmark's own spans count, reset at the window's start;
+    ``measure`` hands the readers a copy taken at its end."""
 
     train_steps: int = 0            # sequential SGD steps of local training
     train_samples: int = 0          # samples those steps trained, all clients
@@ -314,27 +315,32 @@ class Window:
     rounds: int
     wall_s: float
     compiles: int
+    counters: Counters = dataclasses.field(default_factory=Counters)
+    round_walls: List[float] = dataclasses.field(default_factory=list)
 
 
 def measure(episodes: Episodes, seconds: float, monitor: CompileMonitor,
             counters: Counters) -> Window:
     """A closed loop of whole rounds, each ended by ``block_until_ready``,
-    until ``seconds`` have passed."""
+    until ``seconds`` have passed; the window's counts are copied at its end,
+    so that rounds trained after it are not counted."""
     import jax
 
     counters.reset()
     c0 = monitor.backend_compiles
-    n = 0
+    ends = []
     with jax.profiler.TraceAnnotation("bench.window"):
         t0 = time.perf_counter()
         while True:
             episodes.round()
-            n += 1
-            if time.perf_counter() - t0 >= seconds:
+            ends.append(time.perf_counter() - t0)
+            if ends[-1] >= seconds:
                 break
         wall = time.perf_counter() - t0
-    return Window(rounds=n, wall_s=wall,
-                  compiles=monitor.backend_compiles - c0)
+    return Window(rounds=len(ends), wall_s=wall,
+                  compiles=monitor.backend_compiles - c0,
+                  counters=dataclasses.replace(counters),
+                  round_walls=list(np.diff([0.0] + ends)))
 
 
 @dataclasses.dataclass
@@ -358,11 +364,18 @@ def read_metrics(names: List[str], view: RunView) -> Dict[str, dict]:
     return out
 
 
+def matmul_precision(cfg: dict) -> str:
+    """The precision the reference's matrix products and convolutions run
+    at: the one the configuration states, or the highest."""
+    return cfg.get("matmul_precision", "highest")
+
+
 def reference_replay(cell: Cell, clients: list, test: tuple, seeds: dict,
                      records: List[Record], dtype=None):
     """Replay the recorded rounds with the plain reference from the seed's
-    weights, in ``dtype`` (float32 at the highest precision by default).
-    Returns the start weights and, per round, the weights and eval loss."""
+    weights, in ``dtype`` (float32 by default) at the configuration's matmul
+    precision.  Returns the start weights and, per round, the weights and
+    eval loss."""
     import jax
     import jax.numpy as jnp
 
@@ -370,7 +383,7 @@ def reference_replay(cell: Cell, clients: list, test: tuple, seeds: dict,
 
     cfg = cell.config
     dtype = dtype or jnp.float32
-    with jax.default_matmul_precision("highest"):
+    with jax.default_matmul_precision(matmul_precision(cfg)):
         params = jax.jit(lambda k: cell.model.init_params(cfg, k))(
             jax.random.PRNGKey(seeds["params"]))
         treedef = jax.tree_util.tree_structure(params)
@@ -391,14 +404,29 @@ def reference_replay(cell: Cell, clients: list, test: tuple, seeds: dict,
     return w0, out_params, out_losses
 
 
-def compare(records: List[Record], replay) -> Dict[str, float]:
+def reference_losses(cell: Cell, test: tuple, params: list) -> List[float]:
+    """The reference's eval loss of each of the given weights."""
+    import jax
+
+    from bench import reference as ref
+
+    n = cell.config["eval_samples"]
+    with jax.default_matmul_precision(matmul_precision(cell.config)):
+        return [ref.eval_loss(cell.model.reference_apply, p, test[0][:n],
+                              test[1][:n]) for p in params]
+
+
+def compare(cell: Cell, test: tuple, records: List[Record],
+            replay) -> Dict[str, float]:
     """The compared numbers of the program's recorded rounds against the
-    reference's replay of them."""
+    reference's replay of them, and of the losses the program reported
+    against the reference's losses of the same weights."""
     from bench import reference as ref
 
     w0, ref_params, ref_losses = replay
+    own = reference_losses(cell, test, [r.params for r in records])
     return ref.readings(w0, [ref.to_host(r.params) for r in records],
-                        [r.loss for r in records], ref_params, ref_losses)
+                        [r.loss for r in records], ref_params, ref_losses, own)
 
 
 def schedule_gap(hours: List[float], expected: List[float]) -> float:
@@ -450,6 +478,7 @@ def run(cell: Cell, seed: int, seconds: float, trace: bool,
         f"{window.compiles} backend compiles, {episodes.episodes} episodes, "
         f"{episodes.failed} rounds without a feasible schedule, "
         f"restarts took {episodes.restart_s} s")
+    log(f"round walls {[float(w) for w in window.round_walls]} s")
     log(f"first episode's rounds end at {episodes.first_hours} simulated h")
     stats = jax.devices()[0].memory_stats() or {}
     device = {k: chip[k] for k in ("platform", "kind", "count")}
@@ -461,7 +490,7 @@ def run(cell: Cell, seed: int, seconds: float, trace: bool,
         t_read = time.perf_counter()
         tdict = tracereduce.load(trace_dir)
         shutil.rmtree(trace_dir, ignore_errors=True)
-        view = RunView(trace=tdict, window=window, counters=counters,
+        view = RunView(trace=tdict, window=window, counters=window.counters,
                        peak=chip["peak"],
                        flops_per_sample=cell.model.forward_flops_per_sample(
                            cell.config))
@@ -489,8 +518,8 @@ def run(cell: Cell, seed: int, seconds: float, trace: bool,
     if len(records) < cell.reference_rounds:   # the first episode failed early
         numbers = {k: float("inf") for k in cell.limits}
     else:
-        numbers = compare(records, reference_replay(cell, clients, test,
-                                                    seeds, records))
+        numbers = compare(cell, test, records, reference_replay(
+            cell, clients, test, seeds, records))
     numbers["schedule_gap"] = schedule_gap(hours, cell.schedule_hours)
     log(f"reference: {len(records)} rounds in {time.perf_counter() - t_ref} s")
     checks = {k: {"value": numbers[k], "limit": lim}

@@ -1,10 +1,11 @@
 """The plain reference: per-client training, the FL aggregations, and the
 numbers that decide ``correct``.
 
-Nothing here imports the program.  Layers are single ``jax.lax`` calls at
-the highest precision in float32, or plain calls in the control's lower
-precision; every client trains alone (no vmap); the weighted means are taken
-in float64 on the host.
+Nothing here imports the program.  Layers are single ``jax.lax`` calls in
+float32, or in the control's lower precision, at the matmul precision of
+the caller's ``jax.default_matmul_precision``: the configuration's stated
+``matmul_precision``, or the highest where it states none; every client
+trains alone (no vmap); the weighted means are taken in float64 on the host.
 """
 from __future__ import annotations
 
@@ -18,22 +19,26 @@ import numpy as np
 F32 = jnp.float32
 
 
-def _precision(dtype):
-    return jax.lax.Precision.HIGHEST if dtype == F32 else None
-
-
 def conv(x, w, b, dtype):
     """Stride-1 SAME convolution, NHWC x HWIO."""
     y = jax.lax.conv_general_dilated(
         x, w.astype(dtype), (1, 1), "SAME",
-        dimension_numbers=("NHWC", "HWIO", "NHWC"),
-        precision=_precision(dtype))
+        dimension_numbers=("NHWC", "HWIO", "NHWC"))
     return y + b.astype(dtype)
 
 
 def dense(x, w, b, dtype):
-    return jnp.dot(x, w.astype(dtype), precision=_precision(dtype)) \
-        + b.astype(dtype)
+    return jnp.dot(x, w.astype(dtype)) + b.astype(dtype)
+
+
+def conv_transpose2(x, w, b, dtype):
+    """2x2 transposed convolution of stride 2, NHWC x HWIO: input pixel
+    (i, j) writes ``x[i, j] @ w[1 - a, 1 - b]`` to output pixel
+    (2i + a, 2j + b), the taps flipped as ``jax.lax.conv_transpose`` with
+    SAME padding places them."""
+    n, h, wd, _ = x.shape
+    y = jnp.einsum("nhwc,abco->nhawbo", x, w[::-1, ::-1].astype(dtype))
+    return y.reshape(n, 2 * h, 2 * wd, -1) + b.astype(dtype)
 
 
 def max_pool2(x):
@@ -59,7 +64,32 @@ def _sgd(lr):
     return init, update
 
 
-OPTIMIZERS = {"sgd": _sgd}
+def _adam(lr, b1=0.9, b2=0.999, eps=1e-8):
+    """Kingma & Ba (arXiv:1412.6980), Algorithm 1: bias-corrected first and
+    second moments, kept in the parameters' dtype."""
+    def init(p):
+        zeros = jax.tree_util.tree_map(jnp.zeros_like, p)
+        return jnp.zeros((), jnp.int32), zeros, zeros
+
+    def update(p, g, s):
+        t, m, v = s
+        t = t + 1
+        m = jax.tree_util.tree_map(lambda a, d: b1 * a + (1 - b1) * d, m, g)
+        v = jax.tree_util.tree_map(lambda a, d: b2 * a + (1 - b2) * d * d, v, g)
+        c1 = 1 - b1 ** t.astype(F32)
+        c2 = 1 - b2 ** t.astype(F32)
+
+        def step(a, mi, vi):
+            mhat = mi / c1.astype(a.dtype)
+            vhat = vi / c2.astype(a.dtype)
+            return a - lr * mhat / (jnp.sqrt(vhat) + eps)
+
+        return jax.tree_util.tree_map(step, p, m, v), (t, m, v)
+
+    return init, update
+
+
+OPTIMIZERS = {"sgd": _sgd, "adam": _adam}
 
 
 def make_client_trainer(apply: Callable, cfg: dict, dtype=F32) -> Callable:
@@ -200,28 +230,49 @@ def _moving(ref_norms: np.ndarray) -> np.ndarray:
     return ref_norms >= 1e-3 * np.median(ref_norms)
 
 
-def norm_gap(prog: List[np.ndarray], refs: List[np.ndarray]) -> float:
-    """Worst leaf's gap between the program's and the reference's change
-    norm, over the larger of that leaf's and the median leaf's norm."""
-    p = np.asarray([np.linalg.norm(a) for a in prog])
+def _per_leaf(gaps: np.ndarray, refs: List[np.ndarray]) -> float:
+    """Worst moving leaf's gap over the larger of that leaf's and the median
+    leaf's reference norm."""
     r = np.asarray([np.linalg.norm(a) for a in refs])
     keep = _moving(r)
-    return float(np.max(np.abs(p - r)[keep] / np.maximum(r, np.median(r))[keep]))
+    return float(np.max(gaps[keep] / np.maximum(r, np.median(r))[keep]))
+
+
+def norm_gap(prog: List[np.ndarray], refs: List[np.ndarray]) -> float:
+    """Gap between the program's and the reference's change norm, by
+    ``_per_leaf``."""
+    return _per_leaf(np.asarray([abs(np.linalg.norm(a) - np.linalg.norm(b))
+                                 for a, b in zip(prog, refs)]), refs)
+
+
+def dist_gap(prog: List[np.ndarray], refs: List[np.ndarray]) -> float:
+    """Distance between the program's and the reference's change,
+    ||program - reference||, by ``_per_leaf``: where the norms agree, as
+    under Adam's first steps, it still sees a change in another direction."""
+    return _per_leaf(np.asarray([np.linalg.norm(a - b)
+                                 for a, b in zip(prog, refs)]), refs)
 
 
 def readings(w0: List[np.ndarray], prog_params: Sequence[List[np.ndarray]],
              prog_losses: Sequence[float], ref_params: Sequence[List[np.ndarray]],
-             ref_losses: Sequence[float]) -> Dict[str, float]:
+             ref_losses: Sequence[float],
+             own_losses: Sequence[float]) -> Dict[str, float]:
     """The compared numbers after R rounds, from the start weights ``w0``:
-    the eval loss's relative gap, worst over rounds; the first round's change
+    the eval loss's relative gap, worst over rounds, against the reference's
+    replay (``loss_gap``) and against the reference's loss of the program's
+    own weights (``eval_gap``, the evaluation alone); the first round's change
     (the server's first pseudo-gradient) and the change after R rounds, each
-    as a gap of norms."""
+    as a gap of norms (``delta1_gap``, ``deltaR_gap``) and as a distance
+    (``delta1_dist``, ``deltaR_dist``)."""
     d1p = [a - b for a, b in zip(prog_params[0], w0)]
     d1r = [a - b for a, b in zip(ref_params[0], w0)]
     dRp = [a - b for a, b in zip(prog_params[-1], w0)]
     dRr = [a - b for a, b in zip(ref_params[-1], w0)]
     return {
         "loss_gap": max(abs(p - r) / r for p, r in zip(prog_losses, ref_losses)),
+        "eval_gap": max(abs(p - r) / r for p, r in zip(prog_losses, own_losses)),
         "delta1_gap": norm_gap(d1p, d1r),
         "deltaR_gap": norm_gap(dRp, dRr),
+        "delta1_dist": dist_gap(d1p, d1r),
+        "deltaR_dist": dist_gap(dRp, dRr),
     }

@@ -15,6 +15,8 @@ import pytest  # noqa: E402
 TINY = {
     "cnn": dict(train_samples=40 * 40, test_samples=128, eval_samples=128,
                 executed_epochs=1),
+    "unet": dict(input_shape=[16, 16, 3], base=40, depth=2,
+                 train_samples=40 * 32, test_samples=32, eval_samples=32),
 }
 
 # the first episode's round ends at the tiny size, in simulated hours: the
@@ -23,6 +25,8 @@ TINY = {
 TINY_SCHEDULE_HOURS = {
     "cnn-mnist.paper-5x8.fedleo": [5.647564627064001, 14.128819960259674,
                                    19.984354350084956, 28.46715576552315],
+    "unet-deepglobe.paper-5x8.fedleo": [5.64837810210749, 14.129630660781729,
+                                        19.985170643157115, 28.4679696674247],
 }
 
 # what bench.cell.find_chip would return, for a run on this machine's CPU
@@ -32,8 +36,8 @@ CPU_CHIP = {"platform": "cpu", "kind": "cpu", "count": 1,
 
 def tiny_cell(workload: str):
     """The cell as BENCHMARK.json defines it, at a size the CPU holds: fewer
-    samples and one executed epoch, and the schedule those sizes give;
-    widths, mix and limits unchanged."""
+    samples and one executed epoch (the U-Net also at small widths and
+    tiles), and the schedule those sizes give; mix and limits unchanged."""
     from bench import cell as cells
 
     cell = cells.load_cell(workload)

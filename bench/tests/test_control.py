@@ -8,7 +8,8 @@ from bench import calibrate
 from bench.tests.conftest import tiny_cell
 
 
-@pytest.mark.parametrize("workload", ["cnn-mnist.paper-5x8.fedleo"])
+@pytest.mark.parametrize("workload", ["cnn-mnist.paper-5x8.fedleo",
+                                      "unet-deepglobe.paper-5x8.fedleo"])
 def test_control_and_faults_fail_the_limits(workload):
     cell = tiny_cell(workload)
     out = calibrate.calibrate_seed(cell, seed=2_147_483_659, faults=True)

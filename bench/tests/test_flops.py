@@ -1,4 +1,5 @@
 """The FLOP counts the per-layer metrics divide by, against hand counts."""
+import pytest
 
 from bench import cell as cells
 from bench.cell import BENCH, Counters, RunView, Window
@@ -19,14 +20,32 @@ def test_cnn_forward_flops_count_every_tap():
         == 8_482_304
 
 
-def test_cnn_param_count_matches_config():
+def test_unet_forward_flops_count_every_tap():
+    cfg, mod = _config("unet-deepglobe")
+    assert cfg["input_shape"] == [128, 128, 3] and cfg["depth"] == 3
+    enc = (2 * 128 * 128 * 9 * (3 * 16 + 16 * 16)          # 128x128
+           + 2 * 64 * 64 * 9 * (16 * 32 + 32 * 32)         # 64x64
+           + 2 * 32 * 32 * 9 * (32 * 64 + 64 * 64))        # 32x32
+    bottleneck = 2 * 16 * 16 * 9 * 64 * 128
+    # each input pixel meets each of a 2x2 transposed kernel's 4 taps once
+    up = (2 * 16 * 16 * 4 * 128 * 64 + 2 * 32 * 32 * 9 * 128 * 64
+          + 2 * 32 * 32 * 4 * 64 * 32 + 2 * 64 * 64 * 9 * 64 * 32
+          + 2 * 64 * 64 * 4 * 32 * 16 + 2 * 128 * 128 * 9 * 32 * 16)
+    head = 2 * 128 * 128 * 16 * 2
+    assert mod.forward_flops_per_sample(cfg) == enc + bottleneck + up + head \
+        == 858_259_456
+
+
+@pytest.mark.parametrize("name,count", [("cnn-mnist", 421_642),
+                                        ("unet-deepglobe", 285_970)])
+def test_param_count_matches_config(name, count):
     import jax
 
-    cfg, mod = _config("cnn-mnist")
+    cfg, mod = _config(name)
     params = jax.eval_shape(lambda k: mod.init_params(cfg, k),
                             jax.random.PRNGKey(0))
     n = sum(a.size for a in jax.tree_util.tree_leaves(params))
-    assert n == cfg["num_params"] == 421_642
+    assert n == cfg["num_params"] == count
 
 
 def _view(trace, samples=0, wall=1.0, rounds=1):
